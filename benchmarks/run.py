@@ -160,6 +160,9 @@ def main(argv=None) -> None:
                          "by --check (msgs/link always compares at 1%%)")
     args = ap.parse_args(argv)
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import common
 
     if args.smoke:

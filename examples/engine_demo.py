@@ -13,9 +13,12 @@ Same computation as quickstart.py, but:
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import lss, sim, topology
 from repro.engine import EngineConfig, ShardedLSS, sweep_static
 from repro.engine.sweep import cycles_to_accuracy
+
+enable_compile_cache()
 
 n = 4096
 topo = topology.grid(n)  # 64x64 grid, full of cycles

@@ -10,7 +10,10 @@ all-to-all, no spanning tree.
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import lss, topology, wvs
+
+enable_compile_cache()
 
 n = 1024
 topo = topology.grid(n)                      # 32x32 grid: full of cycles

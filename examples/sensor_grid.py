@@ -12,7 +12,10 @@ alternative to convergecast or gossip.
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import lss, sim, topology
+
+enable_compile_cache()
 
 n = 48 * 48
 topo = topology.grid(n)

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 import repro.configs as cfgs
 from repro.models import EncDecConfig, build
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--tokens", type=int, default=48)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = cfgs.get_smoke(args.arch)
     model = build(cfg)

@@ -21,6 +21,7 @@ import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import topology
 from repro.obs import render_controls, render_dashboard
 from repro.service import (ControlPlaneConfig, SLOSpec, Service,
@@ -44,6 +45,7 @@ def main():
                          "(interpret mode off-TPU: bit-exact but slow — "
                          "keep --n small; auto-selected on TPU)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     side = int(round(args.n ** 0.5))
     base = topology.grid(side * side)

@@ -22,6 +22,7 @@ import numpy as np
 import repro.configs as cfgs
 from repro.configs import ShapeCell
 from repro import checkpoint
+from repro.compile_cache import enable_compile_cache
 from repro.core import monitor as monitor_lib
 from repro.core import wvs
 from repro.data import TokenSource
@@ -53,6 +54,7 @@ def main():
     ap.add_argument("--arch", default=None,
                     help="train an assigned arch's smoke config instead")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = cfgs.get_smoke(args.arch) if args.arch else PRESETS[args.preset]
     model = build(cfg)

@@ -1,5 +1,1 @@
 """Reproduction of "Local Thresholding in General Network Graphs"."""
-
-from . import compat as _compat
-
-_compat.ensure_mesh_compat()

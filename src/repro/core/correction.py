@@ -46,8 +46,8 @@ def selective_target(s: wvs.WV, a: wvs.WV, v_set, eps: float = 1e-9) -> wvs.WV:
     Thm.-8 target ``X_ii (+) (+)_k 2 (.) X_ki`` because
     S_i (+) (+)_k A_ik = X_ii (+) (+)_k (X_ki - X_ik) (+) (+)_k (X_ik + X_ki).
     """
-    t_m = s.m + jnp.sum(jnp.where(v_set[..., None], a.m, 0.0), axis=1)
-    t_c = s.c + jnp.sum(jnp.where(v_set, a.c, 0.0), axis=1)
+    t_m = s.m + wvs.slot_sum(jnp.where(v_set[..., None], a.m, 0.0))
+    t_c = s.c + wvs.slot_sum(jnp.where(v_set, a.c, 0.0))
     return wvs.WV(t_m, t_c)
 
 

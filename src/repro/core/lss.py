@@ -40,7 +40,8 @@ from . import correction, stopping, topology, wvs
 __all__ = [
     "LSSConfig", "TopoArrays", "LSSState", "init_state", "cycle",
     "cycle_impl", "clear_slots", "pad_bucket", "metrics", "metrics_impl",
-    "audit_impl", "counter_dtype", "suite_hooks", "COLD_TIMER",
+    "audit_impl", "counter_dtype", "suite_hooks", "live_mask",
+    "mirror_slots", "COLD_TIMER",
 ]
 
 # Send-timer value of a peer that has never sent: far enough in the past
@@ -188,9 +189,26 @@ def clear_slots(state: LSSState, rows, slots) -> LSSState:
                              jnp.asarray(slots, jnp.int32))
 
 
-def _live_mask(topo: TopoArrays, alive: jax.Array) -> jax.Array:
+def live_mask(topo: TopoArrays, alive: jax.Array) -> jax.Array:
     """Valid slots between two live peers (churn = failure of all links)."""
-    return topo.mask & alive[:, None] & alive[topo.nbr]
+    # Gathered slot-major, as in mirror_slots.
+    return topo.mask & alive[:, None] & alive[topo.nbr.T].T
+
+
+def mirror_slots(a: jax.Array, topo: TopoArrays) -> jax.Array:
+    """``a[nbr[i, k], rev[i, k]]`` for every slot ``(i, k)`` of a per-slot
+    array ``a`` (n, D, ...): the value at each slot's mirror across its
+    edge, i.e. where a message into that slot comes from.
+
+    The gather runs slot-major, peers along the minor axis.  TPU compilers
+    take about a second for that form at 10^5 peers, and minutes for the
+    peer-major one (a gather whose output's minor axis is the D slots).
+    """
+    n, D = topo.nbr.shape
+    src = (topo.rev * n + topo.nbr).T  # (D, n) flat slot-major sources
+    lanes = jnp.moveaxis(a, (0, 1), (-1, -2))  # (..., D, n)
+    flat = lanes.reshape(*lanes.shape[:-2], D * n)
+    return jnp.moveaxis(flat[..., src], (-1, -2), (0, 1))
 
 
 def _deliver(state: LSSState, topo: TopoArrays, drop_rate: float, key):
@@ -203,21 +221,19 @@ def _deliver(state: LSSState, topo: TopoArrays, drop_rate: float, key):
     gather, which XLA vectorizes where the equivalent scatter serializes
     — same values in the same slots, bitwise.
     """
-    live = _live_mask(topo, state.alive)
+    live = live_mask(topo, state.alive)
     send = state.pending & live
     if drop_rate > 0.0:
         keep = jax.random.uniform(key, send.shape) >= drop_rate
         delivered = send & keep
     else:
         delivered = send
-    n, D = topo.nbr.shape
-    src = topo.nbr * D + topo.rev  # flat source slot of each in-slot
-    flat = lambda b: b.reshape(n * D, *b.shape[2:])
     # Did my source post a message that survived?  (Padding slots alias
     # arbitrary sources — mask them out on the receiver side.)
-    got = flat(delivered)[src] & topo.mask
-    in_m = jnp.where(got[..., None], flat(state.out_m)[src], state.in_m)
-    in_c = jnp.where(got, flat(state.out_c)[src], state.in_c)
+    got = mirror_slots(delivered, topo) & topo.mask
+    in_m = jnp.where(got[..., None], mirror_slots(state.out_m, topo),
+                     state.in_m)
+    in_c = jnp.where(got, mirror_slots(state.out_c, topo), state.in_c)
     sent = jnp.sum(send)
     return state._replace(
         in_m=in_m,
@@ -376,7 +392,7 @@ def cycle_impl(state: LSSState, topo: TopoArrays, cfg: LSSConfig, decide,
     state = state._replace(rng=rng)
     state, _ = _deliver(state, topo, cfg.drop_rate, kdrop)
 
-    live = _live_mask(topo, state.alive)
+    live = live_mask(topo, state.alive)
     status_viol = corrected = None
     if suite is not None:
         if regions is None:
@@ -454,7 +470,7 @@ def metrics_impl(state: LSSState, topo: TopoArrays, decide, eps=1e-9):
     the ground-truth region id ``f(vec((+)X))``, which per-tenant
     telemetry reports alongside accuracy.
     """
-    live = _live_mask(topo, state.alive)
+    live = live_mask(topo, state.alive)
     s = stopping.status(
         state.x_m, state.x_c, state.out_m, state.out_c, state.in_m, state.in_c, live
     )
@@ -548,12 +564,10 @@ def audit_impl(state: LSSState, topo: TopoArrays, decide, eps=1e-9,
     host-side.
     """
     n, D = topo.nbr.shape
-    live = _live_mask(topo, state.alive)
-    src = topo.nbr * D + topo.rev
-    fl = lambda b: b.reshape(n * D, *b.shape[2:])
-    out_rev_m = fl(state.out_m)[src]
-    out_rev_c = fl(state.out_c)[src]
-    pend_rev = fl(state.pending)[src]
+    live = live_mask(topo, state.alive)
+    out_rev_m = mirror_slots(state.out_m, topo)
+    out_rev_c = mirror_slots(state.out_c, topo)
+    pend_rev = mirror_slots(state.pending, topo)
 
     s = stopping.status(
         state.x_m, state.x_c, state.out_m, state.out_c,
@@ -596,7 +610,8 @@ def audit_impl(state: LSSState, topo: TopoArrays, decide, eps=1e-9,
     check = settled & sm
     a_m = state.out_m + state.in_m
     a_c = state.out_c + state.in_c
-    mismatch = (jnp.any(a_m != fl(a_m)[src], axis=-1)) | (a_c != fl(a_c)[src])
+    mismatch = ((jnp.any(a_m != mirror_slots(a_m, topo), axis=-1))
+                | (a_c != mirror_slots(a_c, topo)))
     edge_bad = jnp.sum(check & mismatch)
     edge_checked = jnp.sum(check)
 

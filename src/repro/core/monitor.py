@@ -35,8 +35,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from . import correction, regions as regions_lib, stopping, wvs
 
-from ..compat import shard_map
-
 __all__ = ["MonitorConfig", "MonitorState", "MeshMonitor"]
 
 
@@ -117,7 +115,7 @@ class MeshMonitor:
         Call inside jit; all comms are ppermute on the monitor axes.
         """
         spec = self._spec
-        f = shard_map(
+        f = jax.shard_map(
             self._step_local,
             mesh=self.mesh,
             in_specs=(MonitorState(spec, spec, spec, spec, spec, spec),

@@ -13,8 +13,9 @@ training-monitor use cases:
 
 Decision functions are pure and vectorized: input (..., d) -> int32 (...).
 ``decide_voronoi`` uses the expansion ||v - c||^2 = ||v||^2 - 2 v.c + ||c||^2
-so the inner loop is a matmul (MXU-friendly; the Pallas kernel in
-``repro.kernels.region_decide`` implements the same contraction).
+with the dot products in coordinate order (:func:`coord_dot`), the same
+float operations the Pallas kernel in ``repro.kernels.region_decide``
+runs.
 """
 
 from __future__ import annotations
@@ -29,6 +30,7 @@ __all__ = [
     "HalfspaceRegions",
     "PackedRegions",
     "PackedSlot",
+    "coord_dot",
     "decide_voronoi",
     "decide_packed",
     "as_packed_slot",
@@ -40,12 +42,25 @@ KIND_VORONOI = 0
 KIND_HALFSPACE = 1
 
 
+def coord_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``sum_j a[..., j] * b[..., j]``, one coordinate at a time.
+
+    Every decision contraction goes through this fixed order of f32
+    multiplies and adds, as the Pallas kernels' VPU loop does, so the
+    reference and fused decisions agree bit for bit on every backend (a
+    matmul would leave the passes and their order to the compiler).
+    """
+    acc = a[..., 0] * b[..., 0]
+    for j in range(1, a.shape[-1]):
+        acc = acc + a[..., j] * b[..., j]
+    return acc
+
+
 def decide_voronoi(v: jax.Array, centers: jax.Array) -> jax.Array:
     """argmin_k ||v - centers[k]||^2 for batched v: (..., d) -> int32 (...)."""
     # ||v||^2 is constant across candidates: argmin needs only the last terms.
-    scores = -2.0 * jnp.einsum("...d,kd->...k", v, centers) + jnp.sum(
-        centers * centers, axis=-1
-    )
+    scores = -2.0 * coord_dot(v[..., None, :], centers) + coord_dot(
+        centers, centers)
     return jnp.argmin(scores, axis=-1).astype(jnp.int32)
 
 
@@ -81,7 +96,7 @@ class HalfspaceRegions(NamedTuple):
         return self.w.shape[0]
 
     def decide(self, v: jax.Array) -> jax.Array:
-        return (jnp.einsum("...d,d->...", v, self.w) >= self.b).astype(jnp.int32)
+        return (coord_dot(v, self.w) >= self.b).astype(jnp.int32)
 
 
 RegionFamily = Callable[[jax.Array], jax.Array]
@@ -97,12 +112,11 @@ def decide_packed(v: jax.Array, kind, centers, cmask, w, b) -> jax.Array:
     Voronoi family padded to Kmax decides bitwise-identically to
     :func:`decide_voronoi` on the unpadded centers.
     """
-    scores = -2.0 * jnp.einsum("...d,kd->...k", v, centers) + jnp.sum(
-        centers * centers, axis=-1
-    )
+    scores = -2.0 * coord_dot(v[..., None, :], centers) + coord_dot(
+        centers, centers)
     scores = jnp.where(cmask, scores, jnp.inf)
     vor = jnp.argmin(scores, axis=-1).astype(jnp.int32)
-    half = (jnp.einsum("...d,d->...", v, w) >= b).astype(jnp.int32)
+    half = (coord_dot(v, w) >= b).astype(jnp.int32)
     return jnp.where(kind == KIND_VORONOI, vor, half)
 
 

@@ -42,8 +42,8 @@ def agreements(out_m, out_c, in_m, in_c) -> wvs.WV:
 def status(x_m, x_c, out_m, out_c, in_m, in_c, mask) -> wvs.WV:
     """S_i = X_ii (+) (+)_j (X_ji (-) X_ij), masked over valid slots."""
     mk = mask[..., None]
-    s_m = x_m + jnp.sum(jnp.where(mk, in_m - out_m, 0.0), axis=1)
-    s_c = x_c + jnp.sum(jnp.where(mask, in_c - out_c, 0.0), axis=1)
+    s_m = x_m + wvs.slot_sum(jnp.where(mk, in_m - out_m, 0.0))
+    s_c = x_c + wvs.slot_sum(jnp.where(mask, in_c - out_c, 0.0))
     return wvs.WV(s_m, s_c)
 
 

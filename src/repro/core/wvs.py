@@ -38,6 +38,7 @@ __all__ = [
     "vec",
     "weight",
     "wsum",
+    "slot_sum",
     "from_vector",
     "allclose",
 ]
@@ -115,6 +116,21 @@ def weight(x: WV) -> jax.Array:
 def wsum(x: WV, axis=0) -> WV:
     """(+)-fold over an axis of a batched WV: the paper's big-oplus."""
     return WV(jnp.sum(x.m, axis=axis), jnp.sum(x.c, axis=axis))
+
+
+def slot_sum(a: jax.Array) -> jax.Array:
+    """Sum over the neighbor-slot axis (axis 1) in slot order.
+
+    Every per-peer slot fold (status, correction target) goes through this
+    one fixed order of float additions, so the reference formulas and the
+    Pallas kernels give the same bits on every backend; ``jnp.sum`` leaves
+    the order to the compiler, which picks a different one per backend and
+    per fusion.
+    """
+    acc = jnp.zeros_like(a[:, 0])
+    for j in range(a.shape[1]):
+        acc = acc + a[:, j]
+    return acc
 
 
 def allclose(x: WV, y: WV, rtol=1e-5, atol=1e-6) -> jax.Array:

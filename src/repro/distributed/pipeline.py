@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..compat import shard_map
-
 __all__ = ["pipeline"]
 
 
@@ -78,7 +76,7 @@ def pipeline(stage_fn: Callable, mesh: Mesh, axis: str = "stage"):
     def apply(stacked_params, x_microbatches):
         in_specs = (jax.tree.map(lambda _: pspec_params, stacked_params),
                     P())
-        g = shard_map(_local, mesh=mesh,
+        g = jax.shard_map(_local, mesh=mesh,
                       in_specs=in_specs, out_specs=P(), check_vma=False)
         return g(stacked_params, x_microbatches)
 

@@ -63,8 +63,8 @@ from typing import NamedTuple, Optional, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType, Mesh
 
-from repro.compat import shard_map
 from repro.core import lss, regions, stopping, topology, wvs
 from repro.kernels import suite as kernel_suite
 
@@ -72,6 +72,22 @@ from . import exchange, partition
 
 __all__ = ["DeviceTopo", "EngineConfig", "ShardedState", "AsyncShardedState",
            "ShardedLSS"]
+
+
+def _take_slots(flat: jax.Array, idx: jax.Array) -> jax.Array:
+    """``flat[idx]`` for a per-slot index array ``idx`` (..., D), gathered
+    slot-major (see :func:`repro.core.lss.mirror_slots` for why)."""
+    return jnp.moveaxis(flat[jnp.moveaxis(idx, -1, 0)], 0, -1)
+
+
+def _intra_deliver(in_buf, out_buf, deliv, tgt_row, rev, intra):
+    """One shard's receive-side gather over its intra-shard edges: for an
+    intra slot the ``(tgt_row, rev)`` map is an involution, so in-slot
+    (j, r) reads its unique source slot (tgt_row[j,r], rev[j,r])."""
+    t = lss.TopoArrays(nbr=tgt_row, mask=intra, rev=rev)
+    got = lss.mirror_slots(deliv, t) & intra
+    cond = got[..., None] if out_buf.ndim > got.ndim else got
+    return jnp.where(cond, lss.mirror_slots(out_buf, t), in_buf)
 
 
 class _LocalTables(NamedTuple):
@@ -314,13 +330,18 @@ class ShardedLSS:
         """Route the halo exchange through shard_map + all_to_all.
 
         The mesh axis size must equal ``num_shards``; state arrays should be
-        device_put with the shard axis over ``axis_name``.
+        device_put with the shard axis over ``axis_name``.  The engine
+        keeps its own copy of ``mesh`` with ``Auto`` axes: the host-side
+        gathers and scatters (:meth:`to_lss_state`, :meth:`set_inputs`,
+        ...) index sharded state eagerly, which the ``Explicit`` axes that
+        ``jax.make_mesh`` builds by default refuse.
         """
         if mesh.shape[axis_name] != self.S:
             raise ValueError(
                 f"mesh axis {axis_name!r} has size {mesh.shape[axis_name]}, "
                 f"engine has {self.S} shards")
-        self._mesh = mesh
+        self._mesh = Mesh(mesh.devices, mesh.axis_names,
+                          axis_types=(AxisType.Auto,) * len(mesh.axis_names))
         self._axis = axis_name
         self._run_jit = jax.jit(self._run_block_collective,
                                 static_argnames=("k",),
@@ -642,7 +663,7 @@ class ShardedLSS:
         keys = jax.vmap(jax.random.split)(state.rng)  # (S, 2, 2)
         rng, kdrop = keys[:, 0], keys[:, 1]
 
-        nbr_alive = state.alive.reshape(S * B)[tables.tgt_pos]
+        nbr_alive = _take_slots(state.alive.reshape(S * B), tables.tgt_pos)
         live = tables.mask & state.alive[..., None] & nbr_alive
         send = state.pending & live
         if cfg.drop_rate > 0.0:
@@ -653,21 +674,12 @@ class ShardedLSS:
             delivered = send
         sent = jnp.sum(send, axis=(1, 2))
 
-        # Shard-local edges: the core's receive-side gather (for an intra
-        # slot the (tgt_row, rev) map is an involution, so in-slot (j, r)
-        # reads its unique source slot (tgt_row[j,r], rev[j,r])).
-        src = tables.tgt_row * D + tables.rev  # (S, B, D) flat source slot
-
-        def gat(in_buf, out_buf, deliv, src_s, ok):
-            flat = out_buf.reshape(B * D, *out_buf.shape[2:])
-            got = deliv.reshape(B * D)[src_s] & ok
-            cond = got[..., None] if flat.ndim > 1 else got
-            return jnp.where(cond, flat[src_s], in_buf)
-
-        in_m = jax.vmap(gat)(state.in_m, state.out_m, delivered, src,
-                             tables.intra)
-        in_c = jax.vmap(gat)(state.in_c, state.out_c, delivered, src,
-                             tables.intra)
+        # Shard-local edges: the core's receive-side gather.
+        deliver = jax.vmap(_intra_deliver)
+        in_m = deliver(state.in_m, state.out_m, delivered, tables.tgt_row,
+                       tables.rev, tables.intra)
+        in_c = deliver(state.in_c, state.out_c, delivered, tables.tgt_row,
+                       tables.rev, tables.intra)
 
         # Cross-shard edges: halo gather -> wire encode -> transpose ->
         # wire decode -> scatter.  The exact wire's encode/decode are the
@@ -741,7 +753,7 @@ class ShardedLSS:
             keys2 = jax.vmap(jax.random.split)(rng)
             rng, kdelay = keys2[:, 0], keys2[:, 1]
 
-        nbr_alive = state.alive.reshape(S * B)[tables.tgt_pos]
+        nbr_alive = _take_slots(state.alive.reshape(S * B), tables.tgt_pos)
         live = tables.mask & state.alive[..., None] & nbr_alive
         send = state.pending & live
         if cfg.drop_rate > 0.0:
@@ -754,18 +766,11 @@ class ShardedLSS:
 
         # Shard-local edges: identical to the sync engine (same shard,
         # same clock — nothing to be stale against).
-        src = tables.tgt_row * D + tables.rev
-
-        def gat(in_buf, out_buf, deliv, src_s, ok):
-            flat = out_buf.reshape(B * D, *out_buf.shape[2:])
-            got = deliv.reshape(B * D)[src_s] & ok
-            cond = got[..., None] if flat.ndim > 1 else got
-            return jnp.where(cond, flat[src_s], in_buf)
-
-        in_m = jax.vmap(gat)(state.in_m, state.out_m, delivered, src,
-                             tables.intra)
-        in_c = jax.vmap(gat)(state.in_c, state.out_c, delivered, src,
-                             tables.intra)
+        deliver = jax.vmap(_intra_deliver)
+        in_m = deliver(state.in_m, state.out_m, delivered, tables.tgt_row,
+                       tables.rev, tables.intra)
+        in_c = deliver(state.in_c, state.out_c, delivered, tables.tgt_row,
+                       tables.rev, tables.intra)
 
         # Cross-shard: publish this cycle's boundary sends (+ their seq
         # stamps) into each shard's ring slot at its own clock...
@@ -898,7 +903,7 @@ class ShardedLSS:
         rng, kdrop = key2[0][None], key2[1]
         alive = sq(state.alive)
         alive_all = jax.lax.all_gather(alive, axis, tiled=True)  # (S*B,)
-        nbr_alive = alive_all[tgt_pos]
+        nbr_alive = _take_slots(alive_all, tgt_pos)
         live = mask & alive[:, None] & nbr_alive
         send = sq(state.pending) & live
         if cfg.drop_rate > 0.0:
@@ -910,13 +915,10 @@ class ShardedLSS:
 
         out_m, out_c = sq(state.out_m), sq(state.out_c)
         # Intra edges as the receive-side gather (see _cycle_full).
-        src = (tgt_row * D + rev).reshape(B * D)
-        got = (delivered.reshape(B * D)[src].reshape(B, D)) & intra
-        in_m = jnp.where(got[..., None],
-                         out_m.reshape(B * D, -1)[src].reshape(B, D, -1),
-                         sq(state.in_m))
-        in_c = jnp.where(got, out_c.reshape(B * D)[src].reshape(B, D),
-                         sq(state.in_c))
+        in_m = _intra_deliver(sq(state.in_m), out_m, delivered, tgt_row,
+                              rev, intra)
+        in_c = _intra_deliver(sq(state.in_c), out_c, delivered, tgt_row,
+                              rev, intra)
 
         buf_m, buf_c, flag = exchange.gather_block(
             out_m, out_c, delivered, halo.send_row, halo.send_slot,
@@ -966,7 +968,7 @@ class ShardedLSS:
             return jax.lax.fori_loop(
                 0, k, lambda _, st: self._cycle_block(st, local_t), state)
 
-        f = shard_map(
+        f = jax.shard_map(
             local, mesh=self._mesh,
             in_specs=(spec,) + (sh,) * 10,
             out_specs=spec, check_vma=False)
@@ -1120,7 +1122,7 @@ class ShardedLSS:
         decide = decide if decide is not None else self.decide
         S, B = self.S, self.B
         fl = lambda a: a.reshape(S * B, *a.shape[2:])
-        nbr_alive = state.alive.reshape(S * B)[tables.tgt_pos]
+        nbr_alive = _take_slots(state.alive.reshape(S * B), tables.tgt_pos)
         live = fl(tables.mask & state.alive[..., None] & nbr_alive)
         x_m, x_c = fl(state.x_m), fl(state.x_c)
         alive = fl(state.alive)

@@ -1,18 +1,20 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-Handle padding to hardware-aligned shapes (peers -> block multiple, vector
-dim -> 128 lanes), dtype normalization, the packed-region table layout,
-and CPU fallback (interpret=True executes the kernel bodies in Python —
-the correctness path this container validates; on TPU the same calls
-compile to Mosaic).
+Handle the lane-dense layout (callers pass peer-major ``(n, D, d)``
+arrays; the kernels take ``(D, d, n)``, peers along the lanes, so a d=2
+statistic is not padded to a 128-lane row in HBM), dtype normalization,
+the packed-region table layout, and the execution mode: Mosaic on TPU,
+``interpret=True`` on any other backend (the kernel bodies run as XLA
+ops, the correctness path the CPU tests validate).  The kernels' grids
+cover a trailing partial block, so no peer padding is copied either.
 
 Region families arrive as a :class:`repro.core.regions.PackedSlot` (or
 anything :func:`repro.core.regions.as_packed_slot` coerces: bare Voronoi
 ``(k, d)`` centers, ``VoronoiRegions``, ``HalfspaceRegions``).  The slot
 is prepared into the kernel table layout:
 
-* ``cthw`` (dp, k+1): lane-padded ``[centers^T | w]`` — the Voronoi
-  contraction and the halfspace projection share one MXU matmul;
+* ``cthw`` (d, k+1): ``[centers^T | w]`` — the Voronoi dot products and
+  the halfspace projection read one table;
 * ``cn`` (1, k): center norms, ``+inf`` on masked padding slots (so a
   padded family decides bitwise like the unpadded one);
 * ``meta`` (1, 4): ``[kind, b, eps, beta]`` — the family kind plus the
@@ -34,21 +36,9 @@ from . import region_decide as _dec
 
 __all__ = ["region_decide", "lss_state", "correction", "prep_slot"]
 
-LANES = 128
-
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
-
-
-def _pad_to(x, mult, axis):
-    n = x.shape[axis]
-    rem = (-n) % mult
-    if rem == 0:
-        return x
-    widths = [(0, 0)] * x.ndim
-    widths[axis] = (0, rem)
-    return jnp.pad(x, widths)
 
 
 def prep_slot(region, eps=1e-9, beta=0.0):
@@ -60,10 +50,9 @@ def prep_slot(region, eps=1e-9, beta=0.0):
     slot = _regions.as_packed_slot(region)
     f32 = jnp.float32
     centers = slot.centers.astype(f32)
-    ct = _pad_to(centers, LANES, 1).T  # (dp, k)
-    wt = _pad_to(slot.w.astype(f32)[None, :], LANES, 1).T  # (dp, 1)
-    cthw = jnp.concatenate([ct, wt], axis=1)  # (dp, k+1)
-    cn = jnp.where(slot.cmask, jnp.sum(centers * centers, -1),
+    cthw = jnp.concatenate([centers.T, slot.w.astype(f32)[:, None]],
+                           axis=1)  # (d, k+1)
+    cn = jnp.where(slot.cmask, _regions.coord_dot(centers, centers),
                    jnp.inf)[None, :]  # (1, k)
     meta = jnp.stack([
         slot.kind.astype(f32),
@@ -77,38 +66,29 @@ def prep_slot(region, eps=1e-9, beta=0.0):
 @jax.jit
 def region_decide(v, region):
     """Packed-family region ids, kernel-accelerated: (n, d) -> (n,) int32."""
-    n = v.shape[0]
-    vp = _pad_to(_pad_to(v.astype(jnp.float32), LANES, 1), _dec.BLOCK_N, 0)
     cthw, cn, meta = prep_slot(region)
-    out = _dec.region_decide_call(vp, cthw, cn, meta, interpret=_interpret())
-    return out[:n, 0]
+    out = _dec.region_decide_call(v.astype(jnp.float32).T, cthw, cn, meta,
+                                  interpret=_interpret())
+    return out[0]
+
+
+def _lanes(a):
+    """Peer-major ``(n, ...)`` -> lane-dense ``(..., n)``."""
+    return jnp.moveaxis(a.astype(jnp.float32), 0, -1)
 
 
 @jax.jit
 def lss_state(x_m, x_c, out_m, out_c, in_m, in_c, mask, region, eps=1e-9):
-    """Fused S/A/violations/decision.  Unpadded moment-form inputs.
+    """Fused S/A/violations/decision.  Peer-major moment-form inputs.
 
     Returns (s_m (n,d), s_c (n,), viol bool (n,D), decision (n,) int32).
     """
-    n, D, d = out_m.shape
-    BN = _state.BLOCK_N
-    f32 = jnp.float32
-    pad0 = lambda a: _pad_to(a, BN, 0)
-    padl = lambda a: _pad_to(a, LANES, a.ndim - 1)
-
-    args = (
-        pad0(padl(x_m.astype(f32))),
-        pad0(x_c.astype(f32)[:, None]),
-        pad0(padl(out_m.astype(f32))),
-        pad0(out_c.astype(f32)),
-        pad0(padl(in_m.astype(f32))),
-        pad0(in_c.astype(f32)),
-        pad0(mask.astype(jnp.int8)),
-    )
     cthw, cn, meta = prep_slot(region, eps=eps)
     s_m, s_c, viol, dec = _state.lss_state_call(
-        *args, cthw, cn, meta, interpret=_interpret())
-    return s_m[:n, :d], s_c[:n, 0], viol[:n].astype(bool), dec[:n, 0]
+        _lanes(x_m), _lanes(x_c[:, None]), _lanes(out_m), _lanes(out_c),
+        _lanes(in_m), _lanes(in_c), mask.T.astype(jnp.int8), cthw, cn, meta,
+        interpret=_interpret())
+    return s_m.T, s_c[0], viol.T.astype(bool), dec[0]
 
 
 @jax.jit
@@ -118,21 +98,12 @@ def correction(s_m, s_c, a_m, a_c, in_m, in_c, v_set, beta=1e-3, eps=1e-9):
     ``beta``/``eps`` may be traced per-query scalars (they ride the meta
     row, not the compiled program).
     """
-    n, D, d = a_m.shape
-    BN = _corr.BLOCK_N
     f32 = jnp.float32
-    pad0 = lambda a: _pad_to(a, BN, 0)
-    padl = lambda a: _pad_to(a, LANES, a.ndim - 1)
     meta = jnp.stack([jnp.zeros((), f32), jnp.zeros((), f32),
                       jnp.asarray(eps, f32),
                       jnp.asarray(beta, f32)]).reshape(1, 4)
     o_m, o_c = _corr.correction_call(
-        pad0(padl(s_m.astype(f32))),
-        pad0(s_c.astype(f32)[:, None]),
-        pad0(padl(a_m.astype(f32))),
-        pad0(a_c.astype(f32)),
-        pad0(padl(in_m.astype(f32))),
-        pad0(in_c.astype(f32)),
-        pad0(v_set.astype(jnp.int8)),
-        meta, interpret=_interpret())
-    return o_m[:n, :, :d], o_c[:n]
+        _lanes(s_m), _lanes(s_c[:, None]), _lanes(a_m), _lanes(a_c),
+        _lanes(in_m), _lanes(in_c), v_set.T.astype(jnp.int8), meta,
+        interpret=_interpret())
+    return jnp.moveaxis(o_m, -1, 0), o_c.T

@@ -1,26 +1,21 @@
-"""Pallas TPU kernel: the packed region decision f as one MXU matmul.
+"""Pallas TPU kernel: the packed region decision f on lane-dense peers.
 
 ``argmin_k ||v - c_k||^2  ==  argmin_k (-2 v . c_k + ||c_k||^2)`` — the
-per-peer Voronoi decision becomes one (BN, dp) x (dp, k+1) matmul against
-the option matrix plus a row argmin.  The packed ``(kind, centers, cmask,
-w, b)`` representation from :mod:`repro.core.regions` rides the same
-contraction: the halfspace normal ``w`` is appended as one extra column of
-the center matrix, so ``v . w`` falls out of the SAME matmul and the
-halfspace decision is a compare against ``b``; masked (padding) center
-slots carry ``+inf`` in the precomputed norm row and contribute exactly
-the +inf score :func:`repro.core.regions.decide_packed` gives them.  A
-per-call ``meta`` row ``[kind, b, eps, beta]`` (see :mod:`.ops`) selects
-the family kind — traced data, so per-query families and knobs never
-recompile, and ``jax.vmap`` batches a service query axis into a leading
-grid dimension with each slot's region table resident in VMEM.
+per-peer Voronoi decision is k dot products plus a running argmin, and
+the halfspace decision of the packed ``(kind, centers, cmask, w, b)``
+representation (:mod:`repro.core.regions`) is one more dot product,
+against the normal ``w``, compared with ``b``.  Masked (padding) center
+slots carry ``+inf`` in the precomputed norm row and never win, exactly
+as in :func:`repro.core.regions.decide_packed`.  A per-call ``meta`` row
+``[kind, b, eps, beta]`` (see :mod:`.ops`) selects the family kind —
+traced data, so per-query families and knobs never recompile, and
+``jax.vmap`` batches a service query axis into a leading grid dimension.
 
-Blocking: peers are tiled BN = 128 rows per grid step (sublane-aligned);
-the vector dim is lane-padded to a multiple of 128 by ``ops.py`` (zero
-padding leaves the contractions unchanged); the (dp, k+1) table and its
-norms live fully in VMEM (k <= a few hundred in every experiment —
-Sec. VI-D sweeps k to 243; ~244*128*4B = 125 KiB).
-VMEM per step ~ BN*dp*4 + (k+1)*dp*4 + BN*(k+1)*4 bytes — ~0.5 MiB at
-defaults.
+Layout: peers run along the 128 lanes (``(d, n)`` blocks of ``BLOCK_N``
+peers), so a statistic of d=2 coordinates costs two sublanes, not a
+128-lane row.  The dot products run on the VPU in coordinate order — the
+same float operations, in the same order, as the reference formulas —
+so fused and reference decisions agree bit for bit on every backend.
 """
 
 from __future__ import annotations
@@ -29,46 +24,65 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-__all__ = ["region_decide_kernel", "region_decide_call"]
+__all__ = ["packed_decide", "region_decide_kernel", "region_decide_call"]
 
-BLOCK_N = 128
+BLOCK_N = 1024
 
 
-def packed_decide(rows, cthw, cn, meta):
-    """Shared decision body: packed-family ids for a block of rows.
+def packed_decide(m, c, tab, cn, meta):
+    """Packed-family ids of a block of weighted vectors.
 
-    ``rows``: (R, dp); ``cthw``: (dp, k+1) = [centers^T | w]; ``cn``:
-    (1, k) center norms with +inf on masked slots; ``meta``: (1, 4)
-    ``[kind, b, eps, beta]``.  Returns int32 (R,).
+    ``m``: (R, d, BN) moments and ``c``: (R, BN) weights (the vector is
+    ``m / c``, zero where ``|c| <= eps``); ``tab``: (d, k+1) =
+    ``[centers^T | w]``; ``cn``: (1, k) center norms with +inf on masked
+    slots; ``meta``: (1, 4) ``[kind, b, eps, beta]``.  Returns int32
+    (R, BN).
     """
-    big = jnp.dot(rows, cthw, preferred_element_type=jnp.float32)
-    scores = -2.0 * big[:, :-1] + cn
-    vor = jnp.argmin(scores, axis=-1).astype(jnp.int32)
-    half = (big[:, -1] >= meta[0, 1]).astype(jnp.int32)
+    d, k1 = tab.shape
+    eps = meta[0, 2]
+    keep = jnp.abs(c) > eps
+    safe = jnp.where(keep, c, 1.0)
+    v = [jnp.where(keep, m[:, j, :] / safe, 0.0) for j in range(d)]
+
+    def dot(col):
+        acc = v[0] * tab[0, col]
+        for j in range(1, d):
+            acc = acc + v[j] * tab[j, col]
+        return acc
+
+    best = -2.0 * dot(0) + cn[0, 0]
+    vor = jnp.zeros(best.shape, jnp.int32)
+    for col in range(1, k1 - 1):
+        score = -2.0 * dot(col) + cn[0, col]
+        better = score < best
+        vor = jnp.where(better, col, vor)
+        best = jnp.where(better, score, best)
+    half = (dot(k1 - 1) >= meta[0, 1]).astype(jnp.int32)
     return jnp.where(meta[0, 0] == 0.0, vor, half)
 
 
-def region_decide_kernel(v_ref, cthw_ref, cn_ref, meta_ref, out_ref):
-    dec = packed_decide(v_ref[...], cthw_ref[...], cn_ref[...], meta_ref[...])
-    out_ref[...] = dec[:, None]
+def region_decide_kernel(v_ref, tab_ref, cn_ref, meta_ref, out_ref):
+    v = v_ref[...][None]  # (1, d, BN)
+    ones = jnp.ones((1, v.shape[-1]), jnp.float32)
+    out_ref[...] = packed_decide(v, ones, tab_ref[...], cn_ref[...],
+                                 meta_ref[...])
 
 
-def region_decide_call(v_pad, cthw, cn, meta, *, interpret: bool):
-    """v_pad: (n_pad, dp); cthw: (dp, k+1); cn: (1, k); meta: (1, 4)
-    -> (n_pad, 1) int32."""
-    n_pad, dp = v_pad.shape
-    k1 = cthw.shape[1]
-    grid = (n_pad // BLOCK_N,)
+def region_decide_call(v, tab, cn, meta, *, interpret: bool):
+    """v: (d, n); tab: (d, k+1); cn: (1, k); meta: (1, 4)
+    -> (1, n) int32."""
+    d, n = v.shape
+    k1 = tab.shape[1]
     return pl.pallas_call(
         region_decide_kernel,
-        grid=grid,
+        grid=(pl.cdiv(n, BLOCK_N),),
         in_specs=[
-            pl.BlockSpec((BLOCK_N, dp), lambda i: (i, 0)),
-            pl.BlockSpec((dp, k1), lambda i: (0, 0)),
+            pl.BlockSpec((d, BLOCK_N), lambda i: (0, i)),
+            pl.BlockSpec((d, k1), lambda i: (0, 0)),
             pl.BlockSpec((1, k1 - 1), lambda i: (0, 0)),
             pl.BlockSpec((1, 4), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((BLOCK_N, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
+        out_specs=pl.BlockSpec((1, BLOCK_N), lambda i: (0, i)),
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
         interpret=interpret,
-    )(v_pad, cthw, cn, meta)
+    )(v, tab, cn, meta)

@@ -24,14 +24,11 @@ import sys
 import time
 import traceback
 
-# Persistent compilation cache speeds up re-lowers during perf iteration.
-cache_dir = os.environ.get("JAX_CACHE_DIR", "/tmp/jax_cache")
-os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", cache_dir)
-
 import jax
 import numpy as np
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.launch import hlo_cost
 from repro.launch.mesh import make_production_mesh
 from repro.models import build
@@ -179,6 +176,8 @@ def main(argv=None):
     ap.add_argument("--all", action="store_true")
     ap.add_argument("--out", default="results/dryrun")
     args = ap.parse_args(argv)
+    # The persistent compilation cache speeds up re-lowers.
+    enable_compile_cache()
 
     outdir = pathlib.Path(args.out)
     outdir.mkdir(parents=True, exist_ok=True)

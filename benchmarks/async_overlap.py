@@ -93,8 +93,8 @@ def _in_flight(tr: InMemoryTracker, skip: int):
     """In-flight intervals [enqueue done, observe synced] per window,
     from the service's own span timestamps (FIFO pairing; ``skip``
     drops the warm-up window)."""
-    enq = [s._t0 + s.seconds for s in tr.spans_named("dispatch")][skip:]
-    syn = [s._t0 + s.seconds for s in tr.spans_named("observe")][skip:]
+    enq = [s.start + s.seconds for s in tr.spans_named("dispatch")][skip:]
+    syn = [s.start + s.seconds for s in tr.spans_named("observe")][skip:]
     merged = []
     for lo, hi in sorted(zip(enq, syn)):
         if merged and lo <= merged[-1][1]:

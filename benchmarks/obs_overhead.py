@@ -14,12 +14,10 @@ the tracker backend —
 * ``prom``   — :class:`repro.obs.PrometheusTextTracker` plus one
   ``expose()`` scrape per dispatch (a live /metrics endpoint's steady
   load).
-* ``traced`` — :class:`repro.obs.InMemoryTracker` with the full PR-7
-  instrumentation switched on: causal spans (always emitted),
-  ``profile_dispatch`` host/device attribution (adds a
-  ``block_until_ready`` fence per dispatch), and an always-firing alert
-  rule evaluated at every observe boundary.  The worst-case tracing
-  window.
+* ``traced`` — :class:`repro.obs.InMemoryTracker` with the full
+  instrumentation switched on: causal spans (always emitted) and an
+  always-firing alert rule evaluated at every observe boundary.  The
+  worst-case tracing window.
 * ``audited`` — :class:`repro.obs.InMemoryTracker` with the audit plane
   sampling EVERY window (``audit_every=1``): the invariant reductions
   fold into the jitted observe program and their scalars ride the same
@@ -78,7 +76,6 @@ def run(full: bool = False):
     tmp.close()
     prom = PrometheusTextTracker()
     traced_cfg = dict(
-        profile_dispatch=True,
         alerts=(AlertRule(name="always", metric="service_queue_depth",
                           above=-1.0),))
     backends = [

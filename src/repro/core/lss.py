@@ -221,26 +221,27 @@ def _deliver(state: LSSState, topo: TopoArrays, drop_rate: float, key):
     gather, which XLA vectorizes where the equivalent scatter serializes
     — same values in the same slots, bitwise.
     """
-    live = live_mask(topo, state.alive)
-    send = state.pending & live
-    if drop_rate > 0.0:
-        keep = jax.random.uniform(key, send.shape) >= drop_rate
-        delivered = send & keep
-    else:
-        delivered = send
-    # Did my source post a message that survived?  (Padding slots alias
-    # arbitrary sources — mask them out on the receiver side.)
-    got = mirror_slots(delivered, topo) & topo.mask
-    in_m = jnp.where(got[..., None], mirror_slots(state.out_m, topo),
-                     state.in_m)
-    in_c = jnp.where(got, mirror_slots(state.out_c, topo), state.in_c)
-    sent = jnp.sum(send)
-    return state._replace(
-        in_m=in_m,
-        in_c=in_c,
-        pending=jnp.zeros_like(state.pending),
-        msgs=state.msgs + sent.astype(state.msgs.dtype),
-    ), sent
+    with jax.named_scope("deliver"):
+        live = live_mask(topo, state.alive)
+        send = state.pending & live
+        if drop_rate > 0.0:
+            keep = jax.random.uniform(key, send.shape) >= drop_rate
+            delivered = send & keep
+        else:
+            delivered = send
+        # Did my source post a message that survived?  (Padding slots
+        # alias arbitrary sources — mask them out on the receiver side.)
+        got = mirror_slots(delivered, topo) & topo.mask
+        in_m = jnp.where(got[..., None], mirror_slots(state.out_m, topo),
+                         state.in_m)
+        in_c = jnp.where(got, mirror_slots(state.out_c, topo), state.in_c)
+        sent = jnp.sum(send)
+        return state._replace(
+            in_m=in_m,
+            in_c=in_c,
+            pending=jnp.zeros_like(state.pending),
+            msgs=state.msgs + sent.astype(state.msgs.dtype),
+        ), sent
 
 
 def _violations(decide, s, a, live, eps):
@@ -321,11 +322,12 @@ def _correction_loop(decide, state, topo, live, active, cfg: LSSConfig,
         _, running, it = carry
         return jnp.any(running) & (it < max_iters)
 
-    v, _, iters = jax.lax.while_loop(
-        cond, body, (v0, running0, jnp.zeros((), jnp.int32))
-    )
-    out_m, out_c = apply_v(v)
-    did_send = active & jnp.any(v, axis=1)
+    with jax.named_scope("correction"):
+        v, _, iters = jax.lax.while_loop(
+            cond, body, (v0, running0, jnp.zeros((), jnp.int32))
+        )
+        out_m, out_c = apply_v(v)
+        did_send = active & jnp.any(v, axis=1)
     return out_m, out_c, v, did_send, iters
 
 
@@ -351,9 +353,10 @@ def suite_hooks(suite, state: LSSState, live, regions, cfg: LSSConfig):
     def corrected(old_s, a0, in_m, in_c, v):
         return suite.corrected(old_s, a0, in_m, in_c, v, cfg.beta, cfg.eps)
 
-    s, viol = status_viol(state.out_m, state.out_c)
-    a0 = stopping.agreements(state.out_m, state.out_c,
-                             state.in_m, state.in_c)
+    with jax.named_scope("status"):
+        s, viol = status_viol(state.out_m, state.out_c)
+        a0 = stopping.agreements(state.out_m, state.out_c,
+                                 state.in_m, state.in_c)
     return status_viol, corrected, (s, a0, viol)
 
 
@@ -392,7 +395,8 @@ def cycle_impl(state: LSSState, topo: TopoArrays, cfg: LSSConfig, decide,
     state = state._replace(rng=rng)
     state, _ = _deliver(state, topo, cfg.drop_rate, kdrop)
 
-    live = live_mask(topo, state.alive)
+    with jax.named_scope("deliver"):
+        live = live_mask(topo, state.alive)
     status_viol = corrected = None
     if suite is not None:
         if regions is None:
@@ -403,13 +407,14 @@ def cycle_impl(state: LSSState, topo: TopoArrays, cfg: LSSConfig, decide,
         # decide (possibly None) is unused downstream: correction_loop
         # only consults it through the default hooks, which are supplied.
     else:
-        s = stopping.status(
-            state.x_m, state.x_c, state.out_m, state.out_c, state.in_m,
-            state.in_c, live
-        )
-        a = stopping.agreements(state.out_m, state.out_c, state.in_m,
-                                state.in_c)
-        viol = _violations(decide, s, a, live, cfg.eps)
+        with jax.named_scope("status"):
+            s = stopping.status(
+                state.x_m, state.x_c, state.out_m, state.out_c, state.in_m,
+                state.in_c, live
+            )
+            a = stopping.agreements(state.out_m, state.out_c, state.in_m,
+                                    state.in_c)
+            viol = _violations(decide, s, a, live, cfg.eps)
         entry = (s, a, viol)
     timer_ok = (state.t - state.last_send) >= cfg.ell
     active = state.alive & timer_ok & jnp.any(viol, axis=1)

@@ -136,11 +136,6 @@ class EngineConfig(NamedTuple):
     # (Voronoi + halfspace) and composes with the service query axis.
     use_kernels: Union[bool, str, None] = None
     halo_slack: float = 1.0  # >1 pads halo width for membership headroom
-    # Wrap every jit dispatch in repro.obs.ProfiledDispatch: host wall vs
-    # device compute split via a block_until_ready fence, published as
-    # gauges (backend="engine" / "engine-mesh").  The fence adds a sync
-    # per dispatch, so this is an opt-in profiling mode, not a default.
-    profile: bool = False
     # Asynchronous gossip execution mode: per-shard clocks, cross-shard
     # messages published into a bounded-staleness ring and read at a
     # receiver-chosen delay in [0, staleness] cycles, per-message seq
@@ -315,9 +310,6 @@ class ShardedLSS:
         self._run_async_jit = jax.jit(self._run_async_block,
                                       static_argnames=("k",),
                                       donate_argnums=self._donate)
-        # Lazily-built ProfiledDispatch over _run_jit (ecfg.profile);
-        # invalidated whenever _run_jit itself is swapped (use_mesh).
-        self._profiled = None
         self._metrics_jit = jax.jit(self._metrics_impl,
                                     static_argnames=("eps",))
         self._audit_jit = jax.jit(self._audit_impl,
@@ -346,7 +338,6 @@ class ShardedLSS:
         self._run_jit = jax.jit(self._run_block_collective,
                                 static_argnames=("k",),
                                 donate_argnums=self._donate)
-        self._profiled = None  # rebuilt over the collective jit on demand
         return self
 
     # -- state -------------------------------------------------------------
@@ -994,12 +985,9 @@ class ShardedLSS:
         per-shard ``engine_shard_halo_bytes_total`` counters and
         ``engine_shard_cut_edges`` gauges for non-noop trackers), and the
         compiled-variant delta (``recompiled``) accumulated into the
-        registry's ``engine_dispatch_recompiles_total`` counter.  With
-        ``EngineConfig.profile`` the jit call runs through a
-        :class:`~repro.obs.ProfiledDispatch` fence, splitting host wall
-        from device compute per dispatch.
+        registry's ``engine_dispatch_recompiles_total`` counter.
         """
-        from repro.obs import NoopTracker, ProfiledDispatch, jit_cache_size
+        from repro.obs import NoopTracker, jit_cache_size
 
         is_async = isinstance(state, AsyncShardedState)
         run_jit = self._run_async_jit if is_async else self._run_jit
@@ -1021,14 +1009,6 @@ class ShardedLSS:
         total_bytes = int(pair.sum())
         wire_w = int(self._tables.halo.send_ok.shape[-1])
         publish = not isinstance(self.tracker, NoopTracker)
-        fn = run_jit
-        if self.ecfg.profile:
-            if self._profiled is None or self._profiled.fn is not fn:
-                backend = ("engine-mesh" if self._mesh is not None
-                           else "engine")
-                self._profiled = ProfiledDispatch(fn, self.tracker,
-                                                  backend=backend)
-            fn = self._profiled
         done = 0
         while done < cycles:
             step = min(k, cycles - done)
@@ -1037,7 +1017,7 @@ class ShardedLSS:
                                    suite=self.suite.name,
                                    mode="async" if is_async else "sync",
                                    transport=transport) as sp:
-                state = fn(state, self._tables, k=step)
+                state = run_jit(state, self._tables, k=step)
                 after = jit_cache_size(run_jit)
                 if (before is not None and after is not None
                         and after > before):

@@ -21,9 +21,6 @@ package is the one interface those observables flow through:
   bounded ring of the last N records for post-mortem JSONL dumps.
 * :mod:`.trace` — :func:`assemble` span records into per-tenant causal
   trees (:class:`TraceForest` / :class:`TenantTrace`).
-* :mod:`.profile` — :class:`ProfiledDispatch` host/device wall
-  attribution via ``block_until_ready`` fencing (optional
-  ``jax.profiler.trace`` sessions).
 * :mod:`.alerts` — :class:`AlertRule` / :class:`AlertEngine`, sustained
   metric predicates emitting ``kind="alert"`` records.
 * :mod:`.schema` — the golden record schema + validators.
@@ -38,11 +35,12 @@ package is the one interface those observables flow through:
   stream, histogram bars, audit summaries (:func:`render_audits`), and
   the causal :func:`trace_view`.
 
-Everything is stdlib-only host-side code: trackers never touch device
-arrays (the :class:`ProfiledDispatch` fence only *moves* a sync the
-caller already pays), so instrumenting the service adds no transfers —
-the numbers all come from the one batched observe round-trip it already
-makes.
+Everything is host-side code: trackers never touch device arrays, so
+instrumenting the service adds no transfers — the numbers all come from
+the one batched observe round-trip it already makes.  Every span also
+opens a ``jax.profiler.TraceAnnotation`` named ``repro.<span>``, so under
+``jax.profiler.trace`` the service's scopes land on the profiler's host
+timeline, on the same clock as the device's ``XLA Ops``.
 """
 
 from .metrics import (Counter, DEFAULT_COUNT_BUCKETS, DEFAULT_TIME_BUCKETS,
@@ -57,7 +55,6 @@ from .tracker import (InMemoryTracker, JsonlTracker, NoopTracker,
 from .alerts import AlertEngine, AlertRule
 from .audit import AuditFaults, AuditReport
 from .flight import FlightRecorder
-from .profile import ProfiledDispatch, profiler_session
 from .push import PushTracker
 from .trace import SpanNode, TenantTrace, TraceForest, assemble
 from .dashboard import (render_audits, render_controls, render_dashboard,
@@ -90,7 +87,6 @@ __all__ = [
     "PER_QUERY_OPTIONAL",
     "PER_QUERY_REQUIRED",
     "PrometheusTextTracker",
-    "ProfiledDispatch",
     "PushTracker",
     "SPAN_OPTIONAL",
     "SPAN_REQUIRED",
@@ -101,7 +97,6 @@ __all__ = [
     "Tracker",
     "assemble",
     "jit_cache_size",
-    "profiler_session",
     "render_audits",
     "render_controls",
     "render_dashboard",

@@ -10,7 +10,10 @@ repo talks to.  It bundles three surfaces:
   drain, membership drain, ingest staging, epoch migration).  Spans are
   always timed with ``time.perf_counter`` — even under
   :class:`NoopTracker` — so callers can read ``span.seconds`` and fold
-  real timings into control records regardless of backend.
+  real timings into control records regardless of backend.  Each span
+  also opens a ``jax.profiler.TraceAnnotation`` named ``repro.<name>``,
+  so under ``jax.profiler.trace`` it appears on the profiler's host
+  plane, on the same clock as the device's ops.
 * ``registry`` — a shared :class:`~repro.obs.metrics.MetricsRegistry` of
   counters / gauges / histograms that policies (SLO eviction, bench
   gates, dashboards) read back.
@@ -37,6 +40,8 @@ import time
 from collections import deque
 from contextlib import contextmanager
 from typing import IO, Any, Dict, Iterable, List, Optional, Union
+
+from jax.profiler import TraceAnnotation
 
 from .metrics import DEFAULT_TIME_BUCKETS, MetricsRegistry
 
@@ -92,6 +97,11 @@ class Span:
         self.parent_id = parent_id
         self.trace = tuple(trace)
         self._t0 = time.perf_counter()
+
+    @property
+    def start(self) -> float:
+        """``time.perf_counter()`` at the scope's entry."""
+        return self._t0
 
     def set(self, key: str, value: Any) -> None:
         self.attrs[key] = value
@@ -151,12 +161,17 @@ class Tracker:
     def span(self, name: str, trace: Iterable[str] = (), **attrs):
         """Open a timed scope.  Nesting is tracked per tracker: a span
         opened while another is active records it as ``parent_id``.
-        ``trace`` names the tenant trace ids this scope serves."""
+        ``trace`` names the tenant trace ids this scope serves.
+
+        The body also runs under a profiler annotation ``repro.<name>``.
+        It gets the name only: attrs would be formatted on every call,
+        profiler or not."""
         parent = self._span_stack[-1].span_id if self._span_stack else None
         sp = Span(name, attrs, parent_id=parent, trace=trace)
         self._span_stack.append(sp)
         try:
-            yield sp
+            with TraceAnnotation("repro." + name):
+                yield sp
         finally:
             sp._stop()
             if self._span_stack and self._span_stack[-1] is sp:

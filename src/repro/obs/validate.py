@@ -71,8 +71,7 @@ def _churn_run(path: str) -> None:
                        above=-1.0, sustain=1),)
     with JsonlTracker(path, keep=False) as tracker:
         with Service(dyn, ServiceConfig(capacity=4, k_max=3, d=2,
-                                        cycles_per_dispatch=4,
-                                        profile_dispatch=True, alerts=rules,
+                                        cycles_per_dispatch=4, alerts=rules,
                                         audit_every=1),
                      tracker=tracker) as svc:
             for spec in heterogeneous_tenants(dyn.n, 4):

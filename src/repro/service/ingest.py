@@ -27,6 +27,7 @@ tenant's stream instead of losing it.
 
 from __future__ import annotations
 
+import time
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
@@ -41,6 +42,7 @@ class UpdateBatch(NamedTuple):
     weights: Optional[np.ndarray] = None  # (m,) weights / weight deltas
     mode: str = "set"  # "set" | "delta"
     query_ids: Optional[Tuple[str, ...]] = None  # None = all active
+    pushed_at: Optional[float] = None  # time.perf_counter() at push
 
 
 class StreamIngest:
@@ -98,7 +100,7 @@ class StreamIngest:
                 f"ingest queue full ({self.max_pending} pending batches)")
         batch = UpdateBatch(who, values, weights, mode,
                             tuple(query_ids) if query_ids is not None
-                            else None)
+                            else None, time.perf_counter())
         self._queue.append(batch)
         return batch
 

@@ -49,6 +49,7 @@ or rebuild engine tables (rebalance), and each recompiles at most once.
 from __future__ import annotations
 
 import os
+import time
 from typing import Dict, NamedTuple, Optional, Tuple, Union
 
 import jax
@@ -57,8 +58,7 @@ import numpy as np
 
 from repro.core import lss, regions, topology, wvs
 from repro.kernels import suite as kernel_suite
-from repro.obs import (AlertEngine, FlightRecorder, ProfiledDispatch,
-                       Tracker, jit_cache_size)
+from repro.obs import AlertEngine, FlightRecorder, Tracker, jit_cache_size
 from repro.obs import audit as obs_audit
 from repro.obs import metrics as obs_metrics
 
@@ -104,10 +104,7 @@ class ServiceConfig(NamedTuple):
     and admit/retire stays zero-recompile (region tables are traced
     data, exactly like the topology tables).
 
-    Observability knobs: ``profile_dispatch`` wraps the compiled step in
-    :class:`~repro.obs.ProfiledDispatch` (host/device wall attribution
-    gauges per dispatch; ``profiler_dir`` additionally runs each
-    dispatch under ``jax.profiler.trace``); ``alerts`` is a tuple of
+    Observability knobs: ``alerts`` is a tuple of
     :class:`~repro.obs.AlertRule` evaluated at every observe boundary;
     ``flight_capacity`` sizes the always-on flight-recorder ring
     (:meth:`Service.dump_flight_recorder`); ``flight_dump_dir`` enables
@@ -138,8 +135,6 @@ class ServiceConfig(NamedTuple):
     admission_overflow: str = "reject"  # "reject" | "evict-oldest"
     control: ControlPlaneConfig = ControlPlaneConfig()  # control plane
     use_kernels: Union[bool, str, None] = None  # kernel suite (see above)
-    profile_dispatch: bool = False  # host/device dispatch attribution
-    profiler_dir: Optional[str] = None  # jax.profiler.trace sessions
     alerts: Tuple = ()  # AlertRule set, evaluated per observe boundary
     flight_capacity: int = 1024  # flight-recorder ring size (records)
     flight_dump_dir: Optional[str] = None  # auto-dump dir (None = manual)
@@ -147,11 +142,8 @@ class ServiceConfig(NamedTuple):
     # host work runs while dispatch K is still on the device; dispatch
     # K's telemetry is finished one tick later (flush() at shutdown
     # drains the last window).  Record CONTENT is identical to sync
-    # mode — only emission is one tick deferred.  profile_sample_every
-    # is ProfiledDispatch's fence cadence: >1 keeps attribution honest
-    # under overlap by only serializing every Nth dispatch.
+    # mode — only emission is one tick deferred.
     overlap: bool = False  # overlap host boundary with in-flight dispatch
-    profile_sample_every: int = 1  # dispatch-attribution fence cadence
     # Audit plane (repro.obs.audit): every Nth dispatch the observation
     # pass additionally evaluates the paper's algebraic invariants as
     # device-side reductions (conservation, edge symmetry, stopping
@@ -662,14 +654,10 @@ class Service:
         donate = (0,) if jax.default_backend() != "cpu" else ()
         self._step = jax.jit(self._step_impl, static_argnames=("k",),
                              donate_argnums=donate)
-        # Profiling wraps the CALL, not the jit: cache probes and
-        # recompile accounting keep reading self._step directly.
-        self._step_call = (
-            ProfiledDispatch(self._step, self._obs,
-                             backend=scfg.backend,
-                             profiler_dir=scfg.profiler_dir,
-                             sample_every=scfg.profile_sample_every)
-            if scfg.profile_dispatch else self._step)
+        # The dispatch calls through this seam, so a caller can wrap the
+        # call (fault injection) while cache probes and recompile
+        # accounting keep reading self._step directly.
+        self._step_call = self._step
         self._observe = jax.jit(self._observe_impl)
         # The audited observe variant is a SEPARATE jitted program: the
         # audit_every cadence is decided host-side between two cached
@@ -952,6 +940,7 @@ class Service:
             # statistic is what an unsuspended tenant would hold.
             parked = self.ingest.take_parked(query_id)
             if parked:
+                self._ingest_waited(parked, time.perf_counter())
                 x_m, x_c, pos = self.backend.x_moments(self.states)
                 slot_arr = np.array([slot], np.int32)
                 for b in parked:
@@ -1333,10 +1322,27 @@ class Service:
         """Queue a per-peer update batch (applied at the next boundary)."""
         return self.ingest.push(who, values, weights, mode, query_ids)
 
-    def _apply_ingest(self) -> int:
+    def _ingest_waited(self, batches, at: float) -> Tuple[int, float]:
+        """Observe each stamped batch's seconds from its push to ``at``,
+        the boundary applying it (a parked batch's replay counts its park
+        time); returns how many were observed and their summed wait."""
+        hist = self.tracker.histogram(
+            "service_ingest_wait_seconds",
+            "seconds from push_updates to the boundary applying the batch")
+        waits = [at - b.pushed_at for b in batches if b.pushed_at is not None]
+        for w in waits:
+            hist.observe(w)
+        return len(waits), sum(waits)
+
+    def _apply_ingest(self) -> Tuple[int, Tuple[int, float]]:
+        """Apply every queued batch.  Returns the batches drained and
+        ``_ingest_waited`` of those applied to an active slot now (a
+        batch only parked is observed at its replay)."""
+        at = time.perf_counter()
         batches = self.ingest.drain()
         if not batches:
-            return 0
+            return 0, (0, 0.0)
+        applied = []
         x_m, x_c, pos = self.backend.x_moments(self.states)
         active = {qid: s for qid, s, _ in self.registry.active_items()}
         for b in batches:
@@ -1352,9 +1358,11 @@ class Service:
                         self.ingest.park(q, b)
                 slots = np.array([active[q] for q in b.query_ids
                                   if q in active], np.int32)
+            if slots.size:
+                applied.append(b)
             x_m, x_c = self.ingest.apply(x_m, x_c, b, slots, pos=pos)
         self.states = self.backend.with_x(self.states, x_m, x_c)
-        return len(batches)
+        return len(batches), self._ingest_waited(applied, at)
 
     # -- the serving loop --------------------------------------------------
     def tick(self, cycles: Optional[int] = None) -> list:
@@ -1365,14 +1373,16 @@ class Service:
         The whole boundary runs inside one ``tick`` root span; every
         host boundary nests under it (``membership_drain`` /
         ``admission_drain`` / ``ingest_apply`` / ``dispatch`` /
-        ``observe``, plus ``epoch_regrow`` / ``epoch_rebalance`` when an
-        epoch fires, and the per-tenant ``activate`` / ``preempt`` /
-        ``resume`` / ``evict`` scopes) — the stream reconstructs into a
-        causal tree via :func:`repro.obs.trace.assemble`.  Timings and
-        work counts also land in the registry and in the next control
-        record's ``spans`` / ``boundary`` maps.  An exception escaping
-        the tick dumps the flight recorder (when ``flight_dump_dir`` is
-        set) before propagating.
+        ``observe``, the sync, and ``observe_emit``, the records and
+        gauges built after it, plus ``epoch_regrow`` /
+        ``epoch_rebalance`` when an epoch fires, and the per-tenant
+        ``activate`` / ``preempt`` / ``resume`` / ``evict`` scopes) —
+        the stream reconstructs into a causal tree via
+        :func:`repro.obs.trace.assemble`.  Timings and work counts also
+        land in the registry and in the next control record's ``spans``
+        / ``boundary`` maps.  An exception escaping the tick dumps the
+        flight recorder (when ``flight_dump_dir`` is set) before
+        propagating.
 
         Returns this dispatch's telemetry records (active slots only).
         Under ``scfg.overlap`` the records returned are the PREVIOUS
@@ -1424,7 +1434,10 @@ class Service:
         self._boundary_spans["admission_drain"] = sp.seconds
         self._boundary_counts["activations"] = n_act
         with tr.span("ingest_apply") as sp:
-            n_batches = self._apply_ingest()
+            n_batches, (waited, wait_s) = self._apply_ingest()
+            if waited:
+                sp.set("waited", waited)
+                sp.set("wait_s", wait_s)
         self._boundary_spans["ingest_apply"] = sp.seconds
         self._boundary_counts["ingest_batches"] = n_batches
 
@@ -1554,6 +1567,15 @@ class Service:
                          if w.audit is not None else None)
         # The window's own observe cost belongs to ITS control record.
         w.spans["observe"] = sp.seconds
+        with self._obs.span("observe_emit", dispatch=w.dispatch):
+            return self._emit_window(w, acc, quiescent, want, msgs,
+                                     corr_iters, audit_raw)
+
+    def _emit_window(self, w: PendingWindow, acc, quiescent, want, msgs,
+                     corr_iters, audit_raw) -> list:
+        """Everything observe does after its sync, on the host: the
+        per-tenant records, gauges, audit records, alerts, the control
+        record and the flight-recorder trigger."""
         reg = self.tracker.registry
         corr_hist = self.tracker.histogram(
             "service_corr_iters",

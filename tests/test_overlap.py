@@ -18,18 +18,15 @@ PendingWindow`.  The contracts under test:
   resume, not dropped;
 * staged epochs (background partition builds) adopt prebuilt engines
   bitwise-equivalently to the synchronous rebuild, including journal
-  catch-up for membership applied while the build was staged;
-* :class:`~repro.obs.ProfiledDispatch`'s ``sample_every`` fences only
-  the sampled calls.
+  catch-up for membership applied while the build was staged.
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.core import lss, regions, sim, topology
-from repro.obs import InMemoryTracker, ProfiledDispatch, jit_cache_size
+from repro.obs import InMemoryTracker, jit_cache_size
 from repro.service import (ControlPlaneConfig, QuerySpec, Service,
                           ServiceConfig)
 from repro.service.overlap import BufferReshape, DoubleBuffer, StagedBuild
@@ -368,30 +365,6 @@ def test_staged_build_surfaces_build_errors_at_take():
 
     ok = StagedBuild(lambda: "engine", label="regrow")
     assert ok.take() == "engine"
-
-
-# ---------------------------------------------------------------------------
-# ProfiledDispatch overlap-aware sampling
-# ---------------------------------------------------------------------------
-
-
-def test_profiled_dispatch_sample_every_fences_sparsely():
-    """sample_every=N fences (and publishes) only every Nth call; the
-    unsampled calls hand back raw futures so overlap is preserved."""
-    tr = InMemoryTracker()
-    step = jax.jit(lambda v: v + 1)
-    pd = ProfiledDispatch(step, tr, backend="test", sample_every=2)
-    v = jnp.zeros((8,))
-    for _ in range(5):
-        v = pd(v)
-    assert pd.calls == 5
-    assert pd.sampled == 3  # calls 0, 2, 4
-    assert float(v[0]) == 5.0  # unsampled calls still computed
-    assert pd.last["host_overhead_frac"] >= 0.0
-    # Only the fenced calls published attribution metrics.
-    mine = [m for m in tr.metrics if m["labels"].get("backend") == "test"]
-    assert len(mine) == 3
-    assert all("dispatch_device_ms" in m["metrics"] for m in mine)
 
 
 # ---------------------------------------------------------------------------

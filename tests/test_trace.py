@@ -1,11 +1,10 @@
-"""Causal tracing, profiling, flight recorder, alerts, push tracker.
+"""Causal tracing, flight recorder, alerts, push tracker.
 
 The PR-7 contract: every span record carries ``span_id``/``parent_id``/
 tenant ``trace`` ids and reassembles into a complete causal forest
 (every dispatch reachable from the admission that minted its trace id);
-``ProfiledDispatch`` splits host from device wall per dispatch on both
-service backends; the flight recorder dumps its ring exactly when an
-SLO violation / eviction / epoch / alert happens; alert rules fire on
+the flight recorder dumps its ring exactly when an SLO violation /
+eviction / epoch / alert happens; alert rules fire on
 sustained predicates only; and ALL of it keeps serving bitwise
 identical to an uninstrumented run.
 """
@@ -17,14 +16,13 @@ import os
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 from repro.core import regions, sim, topology
 from repro.obs import (AlertEngine, AlertRule, FlightRecorder, InMemoryTracker,
-                       MetricsRegistry, NoopTracker, ProfiledDispatch,
-                       PushTracker, assemble, render_histogram, trace_view,
-                       validate_record, validate_stream)
+                       MetricsRegistry, NoopTracker, PushTracker, assemble,
+                       render_histogram, trace_view, validate_record,
+                       validate_stream)
 from repro.service import QuerySpec, Service, ServiceConfig, SLOSpec
 
 ALWAYS = (AlertRule(name="always", metric="service_queue_depth",
@@ -140,40 +138,8 @@ def test_trace_view_renders_and_epoch_spans_fan_out():
 
 
 # ---------------------------------------------------------------------------
-# device-time attribution
+# engine transport spans
 # ---------------------------------------------------------------------------
-
-
-def test_profiled_dispatch_gauges_on_both_backends():
-    for backend in ("core", "engine"):
-        tr = InMemoryTracker()
-        kw = dict(engine_shards=2) if backend == "engine" else {}
-        svc, _ = _serve(tracker=tr, ticks=2, backend=backend,
-                        profile_dispatch=True, **kw)
-        reg = tr.registry
-        for name in ("dispatch_host_ms", "dispatch_device_ms",
-                     "host_overhead_frac"):
-            val = reg.gauge(name).value(backend=backend)
-            assert val is not None and val >= 0.0, (backend, name)
-        frac = reg.gauge("host_overhead_frac").value(backend=backend)
-        assert 0.0 <= frac <= 1.0
-        svc.close()
-
-
-def test_profiled_dispatch_unit_semantics():
-    tr = InMemoryTracker()
-    fn = ProfiledDispatch(jax.jit(lambda x: x * 2), tr, backend="unit")
-    out = fn(jnp.arange(4.0))
-    assert np.array_equal(np.asarray(out), [0.0, 2.0, 4.0, 6.0])
-    assert fn.calls == 1
-    last = fn.last
-    assert last["host_ms"] >= 0 and last["device_ms"] >= 0
-    assert tr.registry.gauge("dispatch_host_ms").value(backend="unit") \
-        == pytest.approx(last["host_ms"])
-    # Publishing goes through log_metrics only: a Noop tracker drops it.
-    noop = NoopTracker()
-    ProfiledDispatch(lambda x: x, noop, backend="unit")(1)
-    assert noop.registry.names() == []
 
 
 def test_engine_mesh_transport_spans_on_collective_path(subproc):
@@ -193,8 +159,7 @@ inputs = wvs.from_vector(jnp.asarray(sample(rng, topo.n)),
 tr = InMemoryTracker()
 mesh = jax.make_mesh((4,), ("shards",))
 eng = ShardedLSS(topo, centers, lss.LSSConfig(),
-                 EngineConfig(num_shards=4, cycles_per_dispatch=4,
-                              profile=True),
+                 EngineConfig(num_shards=4, cycles_per_dispatch=4),
                  tracker=tr).use_mesh(mesh, "shards")
 est = eng.init(inputs, seed=0)
 est = eng.run(est, 8)
@@ -209,8 +174,6 @@ for s in range(4):
     assert halo.value(shard=str(s), transport="all_to_all") > 0
     assert tr.registry.gauge("engine_shard_cut_edges").value(
         shard=str(s)) > 0
-frac = tr.registry.gauge("host_overhead_frac").value(backend="engine-mesh")
-assert frac is not None and 0.0 <= frac <= 1.0
 print("MESH_TRANSPORT_SPANS_OK")
 """, n_devices=4)
     assert "MESH_TRANSPORT_SPANS_OK" in out
@@ -393,7 +356,7 @@ def test_push_tracker_service_parity():
 
 
 def test_full_instrumentation_bitwise_parity(tmp_path):
-    """profile_dispatch + alerts + flight auto-dump + tracing all on,
+    """Alerts + flight auto-dump + tracing all on,
     vs a bare NoopTracker run: records and states bitwise identical."""
     def run(tracker, **cfg_kw):
         svc, out = _serve(tracker=tracker, ticks=4, **cfg_kw)
@@ -402,30 +365,10 @@ def test_full_instrumentation_bitwise_parity(tmp_path):
         return out, states
 
     rec_off, st_off = run(NoopTracker())
-    rec_on, st_on = run(InMemoryTracker(), profile_dispatch=True,
-                        alerts=ALWAYS, flight_capacity=64,
+    rec_on, st_on = run(InMemoryTracker(), alerts=ALWAYS, flight_capacity=64,
                         flight_dump_dir=str(tmp_path))
     assert rec_on == rec_off  # floats exactly equal, trace ids included
     for a, b in zip(st_on, st_off):
-        assert np.array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_engine_profile_flag_bitwise_parity():
-    from repro.core import lss, wvs
-    from repro.engine import EngineConfig, ShardedLSS
-
-    topo = topology.grid(25)
-    centers, sample, _, _ = sim.make_problem(sim.ProblemSpec(n=25, seed=0))
-    rng = np.random.default_rng(0)
-    inputs = wvs.from_vector(jnp.asarray(sample(rng, topo.n)),
-                             jnp.ones((topo.n,), jnp.float32))
-    outs = []
-    for profile, tracker in ((False, None), (True, InMemoryTracker())):
-        eng = ShardedLSS(topo, centers, lss.LSSConfig(),
-                         EngineConfig(num_shards=2, cycles_per_dispatch=4,
-                                      profile=profile), tracker=tracker)
-        outs.append(eng.run(eng.init(inputs, seed=0), 8))
-    for a, b in zip(*outs):
         assert np.array_equal(np.asarray(a), np.asarray(b))
 
 

@@ -103,7 +103,9 @@ def test_step_hlo_names_its_work_by_scope(use_kernels):
         r"^\s*(?:ROOT )?%(\S+) = .*? (\w[\w-]*)\(.*op_name=\"([^\"]*)\"",
         txt, re.M)]
     gathers = [(name, path) for name, kind, path in ops if kind == "gather"]
-    assert len(gathers) >= 3  # the three mirror_slots deliveries
+    # live_mask's, and the mirror_slots deliveries: 2 + d (one per
+    # component plane of out_m).
+    assert len(gathers) >= 3
     for name, path in gathers:
         assert _scopes(path) == {"deliver"}, (name, path)
     loops = [path for _, kind, path in ops

@@ -119,6 +119,8 @@ def main(argv=None) -> int:
             line["breakdown"] = tracefile.breakdown(run.trace)
     line["checks"] = {name: {"value": v, "limit": lim}
                       for name, (v, lim) in checks.items()}
+    print("forgiven by the status error bound (no limit): " + ", ".join(
+        f"{k} {v}" for k, v in out["forgiven"].items()), file=sys.stderr)
     for name, (v, lim) in checks.items():
         print(f"check {name}: {v} (limit {lim})", file=sys.stderr)
     sys.stderr.flush()

@@ -28,6 +28,8 @@ def test_rehearsal_is_correct(workload):
     for name, (value, limit) in out["checks"].items():
         assert value <= limit, (name, value)
     assert out["window_compiles"] == 0
+    spared = out["forgiven"]
+    assert spared["states"] > 0 and spared["most"] <= spared["total"]
     assert out["tenant_cycles"] > 0 and out["attempted"] > 0
     assert out["failed"] == 0
     if workload.endswith(".stream"):

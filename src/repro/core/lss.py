@@ -283,9 +283,12 @@ def _correction_loop(decide, state, topo, live, active, cfg: LSSConfig,
     ``active`` test), saving one full status/violation evaluation per
     cycle.
 
-    Returns ``(out_m, out_c, v, did_send, iters)`` — ``iters`` is the
-    do-while's fixed-point iteration count (scalar int32), the
-    convergence-effort number telemetry aggregates into histograms.
+    Returns ``(out_m, out_c, v, did_send, iters, ran)`` — ``iters`` is
+    the do-while's fixed-point iteration count (scalar int32), the
+    convergence-effort number telemetry aggregates into histograms, and
+    ``ran`` the peers still running summed over those passes (scalar
+    int32): of the ``iters * n`` peer-passes the loop computes, the ones
+    whose result can change.
     """
     n, D = topo.nbr.shape
     if status_viol is None:
@@ -321,24 +324,25 @@ def _correction_loop(decide, state, topo, live, active, cfg: LSSConfig,
         return out_m, out_c
 
     def body(carry):
-        v, running, it = carry
+        v, running, it, ran = carry
         out_m, out_c = apply_v(v)
         _, viol2 = status_viol(out_m, out_c)
         w = viol2 & running[:, None] & ~v
         grew = jnp.any(w, axis=1)
-        return v | w, running & grew, it + 1
+        return (v | w, running & grew, it + 1,
+                ran + jnp.sum(running, dtype=jnp.int32))
 
     def cond(carry):
-        _, running, it = carry
+        _, running, it, _ = carry
         return jnp.any(running) & (it < max_iters)
 
     with jax.named_scope("correction"):
-        v, _, iters = jax.lax.while_loop(
-            cond, body, (v0, running0, jnp.zeros((), jnp.int32))
-        )
+        zero = jnp.zeros((), jnp.int32)
+        v, _, iters, ran = jax.lax.while_loop(
+            cond, body, (v0, running0, zero, zero))
         out_m, out_c = apply_v(v)
         did_send = active & jnp.any(v, axis=1)
-    return out_m, out_c, v, did_send, iters
+    return out_m, out_c, v, did_send, iters, ran
 
 
 # Public alias: the engine re-runs the same do-while per shard block.
@@ -396,10 +400,10 @@ def cycle_impl(state: LSSState, topo: TopoArrays, cfg: LSSConfig, decide,
     into a leading grid dimension and slot updates never recompile.
 
     ``with_stats=True`` (a Python static: it selects the return arity)
-    additionally returns the correction loop's iteration count —
-    ``(state', sent_now, corr_iters)`` — so instrumented callers get the
-    convergence-effort number from the same compiled program at zero
-    extra cost; the default 2-tuple contract is unchanged.
+    additionally returns the correction loop's iteration count and its
+    running-peer sum — ``(state', sent_now, corr_iters, corr_running)``
+    — so instrumented callers get the convergence-effort numbers from
+    the same compiled program; the default 2-tuple contract is unchanged.
     """
     rng, kdrop = jax.random.split(state.rng)
     state = state._replace(rng=rng)
@@ -431,7 +435,7 @@ def cycle_impl(state: LSSState, topo: TopoArrays, cfg: LSSConfig, decide,
     if gate is not None:
         active = active & gate
 
-    out_m, out_c, v, did_send, corr_iters = _correction_loop(
+    out_m, out_c, v, did_send, corr_iters, corr_running = _correction_loop(
         decide, state, topo, live, active, cfg, status_viol=status_viol,
         corrected=corrected, entry=entry)
     pending = state.pending | (v & did_send[:, None])
@@ -443,7 +447,7 @@ def cycle_impl(state: LSSState, topo: TopoArrays, cfg: LSSConfig, decide,
         t=state.t + 1,
     )
     if with_stats:
-        return state, sent_now, corr_iters
+        return state, sent_now, corr_iters, corr_running
     return state, sent_now
 
 

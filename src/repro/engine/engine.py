@@ -609,12 +609,13 @@ class ShardedLSS:
         if gate is not None:
             active = active & gate
 
-        out_m2, out_c2, v, did_send, corr_iters = lss.correction_loop(
-            decide, flat_state, flat_topo, live, active, cfg,
-            status_viol=status_viol, corrected=corrected, entry=entry)
+        out_m2, out_c2, v, did_send, corr_iters, corr_running = (
+            lss.correction_loop(decide, flat_state, flat_topo, live, active,
+                                cfg, status_viol=status_viol,
+                                corrected=corrected, entry=entry))
         pending = v & did_send[:, None]
         new_last = jnp.where(did_send, t, last_send)
-        return out_m2, out_c2, pending, new_last, corr_iters
+        return out_m2, out_c2, pending, new_last, (corr_iters, corr_running)
 
     def _note_unfused(self) -> None:
         """An opaque per-call decide bypassed the fused path: the caller
@@ -646,8 +647,9 @@ class ShardedLSS:
         batch rides the fused kernels.
 
         ``with_stats=True`` (Python static, selects the return arity)
-        returns ``(state', corr_iters)`` — the correction do-while's
-        iteration count, mirroring ``lss.cycle_impl(with_stats=True)``.
+        returns ``(state', corr_iters, corr_running)`` — the correction
+        do-while's iteration count and running-peer sum, mirroring
+        ``lss.cycle_impl(with_stats=True)``.
         """
         cfg = cfg if cfg is not None else self.cfg
         S, B, D = self.S, self.B, self.D
@@ -695,7 +697,7 @@ class ShardedLSS:
 
         # Peer-local update on flattened rows.
         fl = lambda a: a.reshape(S * B, *a.shape[2:])
-        out_m, out_c, pending, last_send, corr_iters = self._peer_update(
+        out_m, out_c, pending, last_send, corr = self._peer_update(
             fl(state.out_m), fl(state.out_c), fl(in_m), fl(in_c),
             fl(state.x_m), fl(state.x_c), fl(live), fl(state.last_send),
             fl(state.alive), state.t, decide=decide, cfg=cfg, gate=gate,
@@ -707,7 +709,7 @@ class ShardedLSS:
             t=state.t + 1, msgs=state.msgs + sent.astype(state.msgs.dtype),
             rng=rng, wire_err_m=err_m, wire_err_c=err_c)
         if with_stats:
-            return state, corr_iters
+            return (state,) + corr
         return state
 
     def _run_block(self, state: ShardedState, tables: DeviceTopo,

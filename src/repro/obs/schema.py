@@ -16,6 +16,13 @@ Five record kinds flow through a tracker's ``log_record`` stream:
     topo_version   int    topology version the dispatch executed under
     trace_id       str    the tenant's causal trace id (minted at admit)
 
+    (from the service's dispatch)
+    corr_iters     int    correction do-while passes this slot needed
+    corr_passes    int    passes the dispatch's vmapped do-while ran
+                          (per cycle the most over the slots, summed)
+    corr_running   int    peers still running, summed over this slot's
+                          passes
+
     (SLO tenants only)
     slo_ok         bool   every declared check passed this window
     slo_violations int    cumulative violation count
@@ -140,6 +147,9 @@ PER_QUERY_REQUIRED = {
 }
 
 PER_QUERY_OPTIONAL = {
+    "corr_iters": _INT,
+    "corr_passes": _INT,
+    "corr_running": _INT,
     "slo_ok": _BOOL,
     "slo_violations": _INT,
     "accuracy_ok": _BOOL,

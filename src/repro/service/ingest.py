@@ -125,7 +125,9 @@ class StreamIngest:
         q = jnp.asarray(slots)[:, None]  # broadcast over the update batch
         vals = jnp.asarray(batch.values, x_m.dtype)
         if batch.mode == "set":
-            w = (jnp.ones((who.shape[0],), x_c.dtype)
+            # Unit weights built on the host: an eager jnp.ones traces
+            # again on every call once any eager vmap has run jnp.ones.
+            w = (np.ones((who.shape[0],), x_c.dtype)
                  if batch.weights is None else jnp.asarray(batch.weights))
             x_m = x_m.at[q, who[None, :]].set(vals * w[:, None])
             x_c = x_c.at[q, who[None, :]].set(w)

@@ -43,7 +43,18 @@ from typing import Any, Callable, Dict, NamedTuple, Optional, Tuple
 
 import jax
 
-__all__ = ["PendingWindow", "DoubleBuffer", "StagedBuild", "BufferReshape"]
+__all__ = ["CorrStats", "PendingWindow", "DoubleBuffer", "StagedBuild",
+           "BufferReshape"]
+
+
+class CorrStats(NamedTuple):
+    """The correction do-while's work in one dispatch (device arrays)."""
+
+    iters: Any  # (Q,) passes each slot needed, summed over the K cycles
+    running: Any  # (Q,) peers still running in those passes, summed
+    # () passes the vmapped loop ran: it runs until every slot stops, so
+    # per cycle the most over the Q slots, summed over the K cycles
+    passes: Any
 
 
 class PendingWindow(NamedTuple):
@@ -57,7 +68,7 @@ class PendingWindow(NamedTuple):
     quiescent: Any  # (Q,) device — per-slot quiescence
     want: Any  # (Q,) device — global correct region
     msgs: Any  # (Q,) device — per-slot sends this window
-    corr_iters: Any  # (Q,) device or None — correction do-while iters
+    corr: Any  # CorrStats of the dispatch — correction do-while work
     active: Tuple[Tuple[str, int], ...]  # (query_id, slot) at launch
     queued: Tuple[str, ...]  # waiting query ids at launch
     preempted: Tuple[str, ...]  # suspended query ids at launch

@@ -69,7 +69,7 @@ from .controlplane import (ActiveView, CapacityManager, ControlPlaneConfig,
                            make_scheduler)
 from .ingest import StreamIngest, UpdateBatch
 from .membership import MembershipQueue
-from .overlap import DoubleBuffer, PendingWindow, StagedBuild
+from .overlap import CorrStats, DoubleBuffer, PendingWindow, StagedBuild
 from .registry import QueryRegistry
 from .telemetry import TelemetrySink
 
@@ -240,13 +240,13 @@ class _CoreBackend:
     def cycle(self, st: lss.LSSState, cfg: lss.LSSConfig, decide, gate, topo,
               pregions=None):
         if self.suite.fused and pregions is not None:
-            st, _, iters = lss.cycle_impl(st, topo, cfg, None, gate=gate,
-                                          suite=self.suite, regions=pregions,
-                                          with_stats=True)
+            st, _, iters, running = lss.cycle_impl(
+                st, topo, cfg, None, gate=gate, suite=self.suite,
+                regions=pregions, with_stats=True)
         else:
-            st, _, iters = lss.cycle_impl(st, topo, cfg, decide, gate=gate,
-                                          with_stats=True)
-        return st, iters
+            st, _, iters, running = lss.cycle_impl(
+                st, topo, cfg, decide, gate=gate, with_stats=True)
+        return st, iters, running
 
     def metrics(self, st: lss.LSSState, decide, eps, topo):
         return lss.metrics_impl(st, topo, decide, eps=eps)
@@ -632,7 +632,7 @@ class Service:
         self._boundary_spans: Dict[str, float] = {}
         self._boundary_counts: Dict[str, int] = {}
         self._recompiles = 0  # cumulative _step cache growth (incl. cold)
-        self._corr_iters = None  # (Q,) per-slot do-while iters last window
+        self._corr = None  # CorrStats of the last dispatch
         self._last_k = scfg.cycles_per_dispatch  # cycles in last window
         self._quiesced_at: Dict[str, int] = {}  # qid -> first quiescent t
         self._total_msgs = {}  # query_id -> host-side exact total
@@ -656,7 +656,9 @@ class Service:
                              donate_argnums=donate)
         # The dispatch calls through this seam, so a caller can wrap the
         # call (fault injection) while cache probes and recompile
-        # accounting keep reading self._step directly.
+        # accounting keep reading self._step directly.  It returns
+        # (states', CorrStats); a wrapped call may give the per-slot
+        # iterations alone in place of the CorrStats.
         self._step_call = self._step
         self._observe = jax.jit(self._observe_impl)
         # The audited observe variant is a SEPARATE jitted program: the
@@ -732,16 +734,20 @@ class Service:
                                   pregions=regions.PackedSlot(*qp.regions))
 
     def _step_impl(self, states, params: qmod.QueryParams, topo, k: int):
-        # The carry also accumulates each slot's correction do-while
-        # iteration count across the K cycles — convergence effort rides
-        # the dispatch it already pays for, no extra device work.
+        # The carry also accumulates the correction do-while's work
+        # across the K cycles (CorrStats) — convergence effort rides the
+        # dispatch it already pays for.
         def body(_, carry):
-            sts, iters = carry
-            sts, it = jax.vmap(
+            sts, c = carry
+            sts, it, running = jax.vmap(
                 lambda st, qp: self._one_cycle(st, qp, topo))(sts, params)
-            return sts, iters + it
+            return sts, CorrStats(iters=c.iters + it,
+                                  running=c.running + running,
+                                  passes=c.passes + jnp.max(it))
         zero = jnp.zeros((states.alive.shape[0],), jnp.int32)
-        return jax.lax.fori_loop(0, k, body, (states, zero))
+        c0 = CorrStats(iters=zero, running=zero,
+                       passes=jnp.zeros((), jnp.int32))
+        return jax.lax.fori_loop(0, k, body, (states, c0))
 
     def _observe_impl(self, states, params: qmod.QueryParams, topo):
         def one(st, qp):
@@ -1456,8 +1462,13 @@ class Service:
         with tr.span("dispatch", trace=self._active_traces(), k=k,
                      backend=self.scfg.backend,
                      suite=info.get("suite"), fused=info.get("fused")) as sp:
-            self.states, self._corr_iters = self._step_call(
+            self.states, corr = self._step_call(
                 self.states, params, topo, k=k)
+            if not isinstance(corr, CorrStats):
+                # A fault-injection wrapper that gives the per-slot
+                # iterations alone (its step runs no cycle).
+                corr = CorrStats(corr, jnp.zeros_like(corr), jnp.max(corr))
+            self._corr = corr
             after = jit_cache_size(self._step)
             if before is not None and after is not None and after > before:
                 sp.set("recompiled", after - before)
@@ -1495,7 +1506,7 @@ class Service:
         return PendingWindow(
             dispatch=self.dispatches, t=self.cycles, k=k,
             acc=acc, quiescent=quiescent, want=want, msgs=msgs,
-            corr_iters=self._corr_iters,
+            corr=self._corr,
             active=tuple((qid, slot) for qid, slot, _spec
                          in self.registry.active_items()),
             queued=tuple(self.admission.queued_ids()),
@@ -1554,25 +1565,19 @@ class Service:
                 trace=tuple(self._trace_ids[qid] for qid, _slot in w.active
                             if qid in self._trace_ids)) as sp:
             # ONE host sync for the whole fleet: metrics, message counts,
-            # the correction-iteration totals and (on sampled windows)
-            # the audit reductions ride the same batched round trip the
-            # observation pass always made.
-            acc, quiescent, want = (np.asarray(w.acc),
-                                    np.asarray(w.quiescent),
-                                    np.asarray(w.want))
-            msgs = np.asarray(w.msgs)
-            corr_iters = (np.asarray(w.corr_iters)
-                          if w.corr_iters is not None else None)
-            audit_raw = (jax.tree_util.tree_map(np.asarray, w.audit)
-                         if w.audit is not None else None)
+            # the correction do-while's counts and (on sampled windows)
+            # the audit reductions come back in one batched transfer
+            # (device_get starts every copy before it waits on any).
+            acc, quiescent, want, msgs, corr, audit_raw = jax.device_get(
+                (w.acc, w.quiescent, w.want, w.msgs, w.corr, w.audit))
         # The window's own observe cost belongs to ITS control record.
         w.spans["observe"] = sp.seconds
         with self._obs.span("observe_emit", dispatch=w.dispatch):
             return self._emit_window(w, acc, quiescent, want, msgs,
-                                     corr_iters, audit_raw)
+                                     corr, audit_raw)
 
     def _emit_window(self, w: PendingWindow, acc, quiescent, want, msgs,
-                     corr_iters, audit_raw) -> list:
+                     corr, audit_raw) -> list:
         """Everything observe does after its sync, on the host: the
         per-tenant records, gauges, audit records, alerts, the control
         record and the flight-recorder trigger."""
@@ -1581,10 +1586,17 @@ class Service:
             "service_corr_iters",
             "correction do-while iterations per slot per dispatch window",
             buckets=obs_metrics.DEFAULT_COUNT_BUCKETS)
+        passes = int(corr.passes)
+        reg.counter(
+            "service_corr_passes_total",
+            "correction do-while passes the vmapped dispatch ran "
+            "(per cycle the most over the slots)").inc(passes)
         records = []
         for qid, slot in w.active:
             sent = int(msgs[slot])
             self._total_msgs[qid] = self._total_msgs.get(qid, 0) + sent
+            iters = int(corr.iters[slot])
+            running = int(corr.running[slot])
             rec = {
                 "dispatch": w.dispatch,
                 "t": w.t,
@@ -1597,6 +1609,9 @@ class Service:
                 "msgs_per_link": sent / w.edges,
                 "topo_version": w.topo_version,
                 "trace_id": self._trace_ids.get(qid, ""),
+                "corr_iters": iters,
+                "corr_passes": passes,
+                "corr_running": running,
             }
             slo_fields = self.slo.observe(qid, rec)
             if slo_fields is not None:
@@ -1621,8 +1636,11 @@ class Service:
             else:
                 if self._quiesced_at.pop(qid, None) is not None:
                     reg.gauge("tenant_quiesced_at_cycles").remove(query=qid)
-            if corr_iters is not None:
-                corr_hist.observe(int(corr_iters[slot]), query=qid)
+            corr_hist.observe(iters, query=qid)
+            reg.counter(
+                "service_corr_running_peers_total",
+                "peers still running, summed over the correction "
+                "do-while's passes, per query").inc(running, query=qid)
             self._obs.log_record(rec)
             records.append(rec)
         # Audit plane: on sampled windows, evaluate the invariant

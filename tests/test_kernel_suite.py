@@ -163,12 +163,17 @@ def _mixed_specs(n, d=2, seed=6):
             mk(regions.VoronoiRegions(fams["voronoi"].centers[:2]), 4)]
 
 
-@pytest.mark.parametrize("backend", ["core", "engine"])
-def test_service_query_axis_fused_bitwise(backend):
+@pytest.mark.parametrize("backend,graph", [
+    pytest.param("core", "grid", id="core"),
+    pytest.param("engine", "grid", id="engine"),
+    pytest.param("core", "chord", id="core-chord"),
+])
+def test_service_query_axis_fused_bitwise(backend, graph):
     """Mixed Voronoi+halfspace tenants (ragged k -> masked padding slots,
     per-query knobs): the fused vmapped dispatch is bitwise-equal to the
-    core-reference service, per-tenant telemetry included."""
-    topo = topology.grid(36)
+    core-reference service, per-tenant telemetry included.  The Chord
+    case takes rows of D = 13 through the kernels' slot loops."""
+    topo = topology.grid(36) if graph == "grid" else topology.chord(128)
     specs = _mixed_specs(topo.n)
     scfg = dict(capacity=6, k_max=6, d=2, cycles_per_dispatch=2)
 
@@ -191,6 +196,8 @@ def test_service_query_axis_fused_bitwise(backend):
             assert a["msgs"] == b["msgs"]
             assert a["quiescent"] == b["quiescent"]
             assert a["region"] == b["region"]
+            assert a["corr_iters"] == b["corr_iters"]
+            assert a["corr_running"] == b["corr_running"]
     for qa, qb in zip(qids_ref, qids_fus):
         sa, sb = svc_ref.snapshot(qa), svc_fus.snapshot(qb)
         _assert_state_bitwise(sb._replace(rng=sa.rng, msgs=sa.msgs), sa,
